@@ -409,9 +409,9 @@ def bench_shard(arch: str = "flsim-logreg", n_traj: int = 16,
                 reps: int = 4, out_path: str = "BENCH_shard.json"):
     """Trajectory-rounds/sec for a device-parallel campaign: the S=16 seed
     grid sharded over a ``n_devices``-lane mesh vs the same campaign's
-    1-device vmap, on fake CPU devices
-    (``XLA_FLAGS=--xla_force_host_platform_device_count=4``;
-    ``benchmarks.run --only shard`` sets the flag itself when absent).
+    1-device vmap, on a 4-device host (a CPU rehearsal fakes one with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=4
+    JAX_PLATFORMS=cpu``).
 
     Both paths run the *same* compiled vmap program over the same S lanes —
     the sharded one just places the leading sweep dim of every plane under a
@@ -438,9 +438,9 @@ def bench_shard(arch: str = "flsim-logreg", n_traj: int = 16,
     if jax.device_count() < n_devices:
         raise RuntimeError(
             f"bench_shard wants {n_devices} devices but only "
-            f"{jax.device_count()} are visible; set "
+            f"{jax.device_count()} are visible; on CPU, set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n_devices} "
-            "before jax initializes (benchmarks.run --only shard does)")
+            "JAX_PLATFORMS=cpu before jax initializes")
     assert rounds % chunk == 0, \
         "rounds must be a multiple of chunk (keeps the timed region free " \
         "of remainder-length compiles)"

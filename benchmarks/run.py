@@ -7,7 +7,6 @@ Usage: PYTHONPATH=src python -m benchmarks.run [--quick] [--only fig8,...]
 """
 import argparse
 import json
-import os
 import pathlib
 import sys
 
@@ -39,23 +38,11 @@ def main() -> None:
     ap.add_argument("--only", default="all")
     args = ap.parse_args()
     selected = (args.only != "all") and args.only.split(",")
-    if selected == ["shard"] and \
-            "xla_force_host_platform_device_count" not in \
-            os.environ.get("XLA_FLAGS", ""):
-        # the shard bench needs a multi-device host; on CPU that means
-        # forcing fake devices BEFORE jax initializes (imported below).
-        # The flag only multiplies the *cpu* platform, so pin the backend
-        # too or an accelerator host would ignore the forcing entirely.
-        # Only when shard is the SOLE selection: forcing would silently
-        # re-platform any co-selected bench onto fake CPU devices, so a
-        # mixed selection must bring its own environment (bench_shard's
-        # RuntimeError says how).
-        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                                   " --xla_force_host_platform_device_count=4"
-                                   ).strip()
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
     from benchmarks import figures, flbench
     import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     q = args.quick
     jobs = {
         # --quick keeps the flsim_small config shape (the host-overhead
@@ -71,15 +58,14 @@ def main() -> None:
         "plan": lambda: flbench.bench_plan(rounds=8 if q else 16),
         # S=16 seed grid sharded over a 4-lane device mesh vs 1-device
         # vmap; --quick keeps S and the mesh (the speedup is the claim).
-        # Selecting it explicitly forces 4 fake CPU devices (above) and
-        # fails hard if they still aren't there (preset XLA_FLAGS /
-        # JAX_PLATFORMS can defeat the forcing); only under the implicit
-        # "all" does a short host skip it, so the other benches still run.
+        # Selecting it explicitly fails hard without 4 devices; only under
+        # the implicit "all" does a short host skip it, so the other
+        # benches still run. A CPU rehearsal brings its own fake devices:
+        # XLA_FLAGS=--xla_force_host_platform_device_count=4 JAX_PLATFORMS=cpu
         "shard": lambda: (
             flbench.bench_shard(rounds=8 if q else 16, reps=3 if q else 4)
             if selected or jax.device_count() >= 4 else
-            print("shard,0,skipped: needs 4 devices — run `benchmarks.run "
-                  "--only shard` (it forces fake CPU devices itself)")),
+            print("shard,0,skipped: needs 4 devices")),
         # fused int8 dequant+weighted-sum vs dequant-first materialize at
         # the memory-bound 1M-param scale; --quick keeps the shape (the
         # traffic ratio is the claim) and only cuts the timed reps

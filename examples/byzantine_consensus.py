@@ -23,11 +23,13 @@ from repro.core.consensus import MultiWorkerAggregator, poison
 from repro.core.rounds import build_spatial_round, init_state
 from repro.core.strategies import get_strategy
 from repro.data.pipeline import SyntheticVision
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model_zoo
 from repro.sharding.axes import AxisCtx
 
 
 def main():
+    enable_compile_cache()
     fl = FLConfig(strategy="fedavg", n_clients=6, local_epochs=1,
                   client_lr=0.1, n_workers=3, byzantine_workers=1,
                   consensus="majority_digest", blockchain="hashchain",

@@ -19,6 +19,7 @@ from repro.core import determinism
 from repro.core.rounds import build_spatial_round, init_state
 from repro.core.strategies import get_strategy
 from repro.data.pipeline import SyntheticVision
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model_zoo
 from repro.sharding.axes import AxisCtx
 
@@ -33,6 +34,7 @@ def divergence(params):
 
 
 def main():
+    enable_compile_cache()
     fl = FLConfig(strategy="gossip", topology="decentralized", n_clients=8,
                   local_epochs=2, client_lr=0.05, gossip_steps=1, seed=0)
     cfg = get_config("flsim-mlp")

@@ -12,6 +12,7 @@ import sys
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 
 from repro.core.jobs import load_job
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.executor import Executor
 
 JOB = {
@@ -37,6 +38,7 @@ JOB = {
 
 
 def main():
+    enable_compile_cache()
     job = load_job(JOB)
     # scale the CNN for CPU quickness (same as the benches)
     job.model = job.model.__class__(job.model.cfg.replace(d_model=32, d_ff=64),

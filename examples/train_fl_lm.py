@@ -26,6 +26,7 @@ from repro.core import determinism
 from repro.core.rounds import build_temporal_round, init_state
 from repro.core.strategies import get_strategy
 from repro.data.pipeline import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.metrics.logger import PerformanceLogger
 from repro.models import model_zoo
 from repro.sharding.axes import AxisCtx
@@ -39,6 +40,7 @@ SCALES = {
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-34b")
     ap.add_argument("--scale", default="tiny", choices=sorted(SCALES))

@@ -339,12 +339,6 @@ def _quant_agg_impl(name) -> None:
         frame["last_impl"] = name
 
 
-def _is_batched(*arrays) -> bool:
-    """True when tracing under a jax.vmap (campaign lane axis)."""
-    from jax.interpreters import batching
-    return any(isinstance(a, batching.BatchTracer) for a in arrays)
-
-
 def _quant_agg_fused(qdeltas, scales, weights):
     """Fused dequant + weighted sum: the client accumulation is unrolled
     (C is a static shape), so XLA fuses the whole chain into ONE pass over
@@ -382,19 +376,47 @@ def _quant_agg_dequant_first(qdeltas, scales, weights):
 
 
 def _quant_agg_pallas(qdeltas, scales, weights, interpret: bool):
-    """Pad-and-mask wrapper around the Pallas kernel: N is padded up to a
-    whole number of kernel tiles with zero blocks (q == 0 AND scale == 0, so
-    padding contributes exactly 0.0) and the pad lanes are sliced off."""
-    from repro.kernels.quant_aggregate import quant_aggregate as _k
+    """Pad-and-mask wrapper around the Pallas kernel: the block rows are
+    padded up to whole kernel tiles and the clients up to whole client
+    chunks, all with zeros (q == 0, scale == 0 and weight == 0 contribute
+    exactly +0.0), and the pad lanes are sliced off."""
+    from repro.kernels import quant_aggregate as qa
     C, N = qdeltas.shape
-    qblock = N // scales.shape[-1]
-    block_n = qblock * max(1, 4096 // qblock)
-    pad = (-N) % block_n
-    if pad:
-        qdeltas = jnp.pad(qdeltas, ((0, 0), (0, pad)))
-        scales = jnp.pad(scales, ((0, 0), (0, pad // qblock)))
-    out = _k(qdeltas, scales, weights, block_n=block_n, interpret=interpret)
-    return out[:N] if pad else out
+    nblocks = scales.shape[-1]
+    qblock = N // nblocks
+    cb, rows = qa.tile_shape(C, qblock, nblocks)
+    pad_b, pad_c = (-nblocks) % rows, (-C) % cb
+    if pad_b or pad_c:
+        qdeltas = jnp.pad(qdeltas, ((0, pad_c), (0, pad_b * qblock)))
+        scales = jnp.pad(scales, ((0, pad_c), (0, pad_b)))
+        weights = jnp.pad(weights, (0, pad_c))
+    out = qa.quant_aggregate(qdeltas, scales, weights, cb=cb, rows=rows,
+                             interpret=interpret)
+    return out[:N] if pad_b else out
+
+
+def _quant_agg_vmap_rule(axis_size, in_batched, qdeltas, scales, weights):
+    """custom_vmap rule of the Pallas path: a vmapped lane axis (campaign
+    lanes) routes to the fused jnp path, counted and warned."""
+    import warnings
+    _quant_agg_bump("batched_fallbacks")
+    _quant_agg_impl("jnp-fused(vmap-fallback)")
+    warnings.warn(
+        "quant_aggregate: Pallas kernel requested under a vmapped lane "
+        "axis; using the fused jnp path for this trace", stacklevel=2)
+    args = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+            for a, b in zip((qdeltas, scales, weights), in_batched)]
+    return jax.vmap(_quant_agg_fused)(*args), True
+
+
+@functools.cache
+def _pallas_entry(interpret: bool):
+    """The Pallas path with its vmap rule attached (one per interpret flag,
+    so the traced function takes arrays only)."""
+    entry = jax.custom_batching.custom_vmap(
+        functools.partial(_quant_agg_pallas, interpret=interpret))
+    entry.def_vmap(_quant_agg_vmap_rule)
+    return entry
 
 
 def quant_aggregate(qdeltas, scales, weights):
@@ -404,8 +426,8 @@ def quant_aggregate(qdeltas, scales, weights):
     rows with the dequant-first reference path):
 
     - ``pallas``/``interpret`` — Pallas kernel (compiled / interpret=True),
-      via the pad-and-mask wrapper; under a campaign ``vmap`` falls back to
-      the fused jnp path with a logged warning (bitwise-identical result);
+      via the pad-and-mask wrapper; its ``custom_vmap`` rule sends a
+      campaign lane ``vmap`` to the fused jnp path with a logged warning;
     - ``jnp`` (CPU default)   — the fused jnp expression.
     """
     mode = os.environ.get("REPRO_QUANT_AGG", "fused")
@@ -417,18 +439,8 @@ def quant_aggregate(qdeltas, scales, weights):
         return _quant_agg_dequant_first(qdeltas, scales, weights)
     impl = backend()
     if impl in ("pallas", "interpret"):
-        if _is_batched(qdeltas, scales, weights):
-            import warnings
-            _quant_agg_bump("batched_fallbacks")
-            _quant_agg_impl("jnp-fused(vmap-fallback)")
-            warnings.warn(
-                "quant_aggregate: Pallas kernel requested under a vmapped "
-                "lane axis; using the fused jnp path for this trace "
-                "(bitwise-identical result)", stacklevel=2)
-            return _quant_agg_fused(qdeltas, scales, weights)
         _quant_agg_impl(impl)
-        return _quant_agg_pallas(qdeltas, scales, weights,
-                                 interpret=(impl == "interpret"))
+        return _pallas_entry(impl == "interpret")(qdeltas, scales, weights)
     _quant_agg_impl("jnp-fused")
     return _quant_agg_fused(qdeltas, scales, weights)
 

@@ -129,8 +129,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     shape = SHAPES[shape_name]
     t0 = time.time()
     built = steps_mod.make_step_from_cfg(cfg, shape, mesh)
-    from repro.launch.mesh import mesh_context
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(built.fn,
                           donate_argnums=built.donate).lower(*built.inputs)
         t_lower = time.time() - t0

@@ -12,13 +12,9 @@ import jax
 
 
 def _mesh(shape, axes):
-    """jax.make_mesh across jax versions: AxisType (and the axis_types
-    kwarg) only exist on newer jax; older jax is implicitly all-Auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis Auto (sharding propagated by XLA)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -80,15 +76,6 @@ def shard_lanes(tree, mesh, axes=None):
         return jax.tree.map(lambda t: jax.device_put(t, lane), tree)
     return {k: jax.device_put(v, repl if axes.get(k) is None else lane)
             for k, v in tree.items()}
-
-
-def mesh_context(mesh):
-    """Ambient-mesh context across jax versions: ``jax.set_mesh`` on newer
-    jax; on older jax the Mesh object is itself the context manager."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
 
 
 # TPU v5e hardware constants (roofline):

@@ -12,6 +12,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -23,11 +24,6 @@ from repro.models.attention import KVCache, LatentCache
 from repro.models.ssm import MLSTMState, MambaState, SLSTMState
 from repro.sharding import specs as sspecs
 from repro.sharding.axes import AxisCtx
-
-try:
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # newer jax
-    from jax.sharding import shard_map
 
 
 def mesh_ctx(mesh) -> AxisCtx:
@@ -252,7 +248,7 @@ def make_train_step(cfg: ModelConfig, shape: ShapeConfig, mesh,
         mesh=mesh,
         in_specs=(sstate_specs, bspecs, wspec, P(None)),
         out_specs=(sstate_specs, {"loss": P()}),
-        check_rep=False)
+        check_vma=False)
     return BuiltStep(fn, (state, batch, weights, rng), "train", donate=(0,))
 
 
@@ -284,7 +280,7 @@ def make_prefill_step(cfg: ModelConfig, shape: ShapeConfig, mesh) -> BuiltStep:
         in_specs=(jax.tree.map(lambda s: s.sharding.spec, params),
                   jax.tree.map(lambda s: s.sharding.spec, batch)),
         out_specs=(cache_specs, P(bspec, None)),
-        check_rep=False)
+        check_vma=False)
     return BuiltStep(fn, (params, batch), "prefill")
 
 
@@ -313,7 +309,7 @@ def make_decode_step(cfg: ModelConfig, shape: ShapeConfig, mesh) -> BuiltStep:
         in_specs=(jax.tree.map(lambda s: s.sharding.spec, params),
                   P(bspec), cache_specs, P(bspec)),
         out_specs=(logits_spec, cache_specs),
-        check_rep=False)
+        check_vma=False)
     return BuiltStep(fn, (params, tokens, cache_structs, length), "decode",
                      donate=(2,))
 

@@ -156,16 +156,8 @@ class Executor:
         """How many distinct XLA programs this executor has compiled —
         the planner's bucket-count contract ("a 24-point grid with 4
         signatures compiles 4 programs") is asserted against this. Reads
-        the jit caches when the jax version exposes them; falls back to
-        one per (program, scan length) entry."""
-        total = 0
-        for prog in self._programs.values():
-            size = getattr(prog, "_cache_size", None)
-            try:
-                total += int(size()) if callable(size) else 1
-            except Exception:
-                total += 1
-        return total
+        the jit caches."""
+        return sum(prog._cache_size() for prog in self._programs.values())
 
     def _round_program(self, n_rounds: int):
         """Jitted n_rounds-launch; at most two lengths ever compile (the

@@ -35,9 +35,7 @@ class AxisCtx:
     def size(self, name: Optional[str]) -> int:
         if name is None:
             return 1
-        if hasattr(jax.lax, "axis_size"):
-            return jax.lax.axis_size(name)
-        return jax.core.axis_frame(name)         # older jax: returns the size
+        return jax.lax.axis_size(name)
 
     def index(self, name: Optional[str]):
         if name is None:

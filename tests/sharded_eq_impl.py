@@ -23,7 +23,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import ShapeConfig, get_config
 from repro.configs.reduce import reduced_config
 from repro.launch import steps as steps_mod
-from repro.launch.mesh import make_test_mesh, mesh_context
+from repro.launch.mesh import make_test_mesh
 from repro.models import model_zoo
 from repro.sharding.axes import AxisCtx
 
@@ -65,7 +65,7 @@ def check_train(arch, mesh_name, B=8, S=32):
         lambda t: (t % cfg.vocab_size) if t.dtype == jnp.int32 else t, batch)
     weights = jnp.ones_like(weights)
     rng = jnp.zeros((2,), jnp.uint32)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         new_state, metrics = jax.jit(built.fn)(state, batch, weights, rng)
         sharded_loss = float(metrics["loss"])
         sharded_params = jax.tree.map(np.asarray, new_state["params"])
@@ -119,7 +119,7 @@ def check_decode(arch, mesh_name, B=8, S=32):
     params, tokens, caches, length = materialize(built.inputs)
     tokens = tokens % cfg.vocab_size
     length = jnp.full_like(length, S - 1)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         logits, _ = jax.jit(built.fn)(params, tokens, caches, length)
         logits_sh = np.asarray(logits).astype(np.float32)
 

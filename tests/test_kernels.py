@@ -17,6 +17,7 @@ from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.decode_attention import decode_attention_fwd
 from repro.kernels.quant_aggregate import quant_aggregate as pallas_quant_agg
+from repro.kernels.quant_aggregate import tile_shape
 from repro.kernels.rmsnorm import rmsnorm as pallas_rmsnorm
 
 KEY = jax.random.PRNGKey(0)
@@ -161,14 +162,16 @@ def test_rmsnorm_interpret_vs_ref(shape, dtype):
 
 
 @pytest.mark.parametrize("C,N,qblock", [(4, 8192, 256), (10, 4096, 128),
-                                        (32, 16384, 512)])
+                                        (32, 16384, 512), (6, 384 * 256, 256),
+                                        (128, 256 * 256, 256)])
 def test_quant_aggregate_interpret_vs_ref(C, N, qblock):
     ks = jax.random.split(KEY, 3)
     qd = jax.random.randint(ks[0], (C, N), -127, 128, jnp.int8)
     sc = jax.random.uniform(ks[1], (C, N // qblock), jnp.float32, 1e-4, 1e-2)
     w = jax.random.uniform(ks[2], (C,), jnp.float32)
     w = w / w.sum()
-    got = pallas_quant_agg(qd, sc, w, block_n=2048, interpret=True)
+    cb, rows = tile_shape(C, qblock, N // qblock)
+    got = pallas_quant_agg(qd, sc, w, cb=cb, rows=rows, interpret=True)
     want = ref.quant_aggregate_ref(qd, sc, w)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
@@ -223,10 +226,20 @@ def test_quant_aggregate_pad_and_mask_non_divisible(N, qblock, monkeypatch):
                                rtol=1e-5, atol=1e-6)
 
 
+def test_quant_aggregate_pads_client_chunks(monkeypatch):
+    """A cohort above one client chunk that the chunk does not divide is
+    padded with zero-weight clients and reduced chunk by chunk."""
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", "interpret")
+    qd, sc, w = _qagg_inputs(200, 1280, 256)
+    got = ops.quant_aggregate(qd, sc, w)
+    want = ref.quant_aggregate_ref(qd, sc, w)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+
+
 def test_quant_aggregate_vmap_falls_back_to_fused(monkeypatch):
-    """Under a campaign lane vmap the Pallas wrapper can't run (pallas_call
-    doesn't trace through a batched dim here); the dispatcher must fall
-    back to the fused jnp path — warning + counter, bitwise per-lane."""
+    """Under a campaign lane vmap the Pallas path's custom_vmap rule must
+    fall back to the fused jnp path — warning + counter, bitwise per-lane."""
     monkeypatch.setenv("REPRO_KERNEL_IMPL", "interpret")
     L, C, N, qblock = 3, 4, 2048, 256
     ks = jax.random.split(KEY, 3)
@@ -246,20 +259,28 @@ def test_quant_aggregate_vmap_falls_back_to_fused(monkeypatch):
             np.asarray(ops._quant_agg_fused(qd[lane], sc[lane], w[lane])))
 
 
+def test_quant_aggregate_vmap_broadcasts_unbatched_args(monkeypatch):
+    """Lanes that share the weights (an unbatched operand) take the same
+    fallback; the rule broadcasts the shared operand across lanes."""
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", "interpret")
+    L, C, N, qblock = 2, 3, 1024, 256
+    ks = jax.random.split(KEY, 2)
+    qd = jax.random.randint(ks[0], (L, C, N), -127, 128, jnp.int8)
+    sc = jax.random.uniform(ks[1], (L, C, N // qblock), jnp.float32,
+                            1e-4, 1e-2)
+    w = jnp.array([0.5, 0.25, 0.25], jnp.float32)
+    with ops.quant_agg_scope() as frame, \
+            pytest.warns(UserWarning, match="vmapped"):
+        got = jax.vmap(ops.quant_aggregate, in_axes=(0, 0, None))(qd, sc, w)
+    assert frame["batched_fallbacks"] == 1
+    for lane in range(L):
+        np.testing.assert_array_equal(
+            np.asarray(got[lane]),
+            np.asarray(ops._quant_agg_fused(qd[lane], sc[lane], w)))
+
+
 def test_quant_aggregate_rejects_unknown_mode(monkeypatch):
     monkeypatch.setenv("REPRO_QUANT_AGG", "fussed")
     qd, sc, w = _qagg_inputs(2, 1024, 256)
     with pytest.raises(ValueError, match="REPRO_QUANT_AGG"):
         ops.quant_aggregate(qd, sc, w)
-
-
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="compiled Pallas path needs a TPU backend")
-def test_quant_aggregate_pallas_compiled_vs_ref():
-    """TPU-only: the compiled (non-interpret) kernel against the jnp
-    oracle — a capability skip on CPU runners, never a silent pass."""
-    qd, sc, w = _qagg_inputs(8, 1 << 16, 256)
-    got = pallas_quant_agg(qd, sc, w, block_n=4096, interpret=False)
-    want = ref.quant_aggregate_ref(qd, sc, w)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-6)

@@ -121,6 +121,32 @@ def test_compressed_campaign_bitwise_equals_single_runs():
     _assert_lanes_match_singles(camp, mk)
 
 
+@pytest.mark.xfail(strict=True, raises=AssertionError, reason=(
+    "known breach: on flsim-cnn the compressed spatial round program under "
+    "the campaign vmap differs from the single run by ulps in the conv "
+    "biases b2/b3 after one round (fedavg, temporal placement and flsim-mlp "
+    "stay bitwise); source not isolated"))
+def test_compressed_cnn_lane_bitwise_equals_single_run():
+    """The lane == single run contract on the conv model: spatial int8, one
+    round, four clients, lane 0 against the seed-0 single run."""
+    def mk(seeds=None):
+        raw = _raw({"seed": 0}, strategy="compressed", rounds=1, chunk=1)
+        raw["model"]["arch"] = "flsim-cnn"
+        raw["dataset"]["n_items"] = 256
+        raw["strategy"]["train_params"].update(
+            {"placement": "spatial", "compression": "int8"})
+        raw.pop("runtime")
+        if seeds:
+            raw["sweep"] = {"seeds": seeds}
+        return raw
+
+    state, _ = Executor(load_job(mk())).scaffold().run()
+    camp = CampaignExecutor(load_job(mk([0, 1]))).scaffold()
+    camp.run()
+    _assert_bitwise_equal(jax.tree.map(np.asarray, state["params"]),
+                          camp.trajectory_params(0))
+
+
 def test_compression_is_a_categorical_sweep_axis():
     """A compression axis buckets by program signature (dense vs packed
     aggregation are different traced programs) — it must parse, expand,
