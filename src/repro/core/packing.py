@@ -16,6 +16,9 @@ computed against either representation therefore agree bit for bit.
 
 The pack spec (offsets, padded sizes) is a pure function of the tree
 *structure*, known at trace time; nothing here inspects runtime values.
+Every traced function here runs under the ``fl.pack`` scope, so the device
+ops of quantization, block scales and the flat layout carry it in a
+profile.
 """
 from __future__ import annotations
 
@@ -58,6 +61,7 @@ def packed_nbytes(template, qblock: int = QBLOCK) -> int:
     return n + 4 * n_blocks
 
 
+@jax.named_scope("fl.pack")
 def pack_tree(tree, qblock: int = QBLOCK) -> jax.Array:
     """Flatten a pytree to (N,) f32, zero-padding each leaf to whole blocks."""
     pieces = []
@@ -68,6 +72,7 @@ def pack_tree(tree, qblock: int = QBLOCK) -> jax.Array:
     return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces)
 
 
+@jax.named_scope("fl.pack")
 def quantize_tree(tree, qblock: int = QBLOCK) -> PackedDelta:
     """Block-quantize a delta pytree into the kernel's packed layout."""
     from repro.kernels import ref as kref
@@ -75,6 +80,7 @@ def quantize_tree(tree, qblock: int = QBLOCK) -> PackedDelta:
     return PackedDelta(q=q, scale=sc)
 
 
+@jax.named_scope("fl.pack")
 def dequant_flat(pd: PackedDelta) -> jax.Array:
     """(N,) f32 dequantized values; same arithmetic order as the unpacked
     reference roundtrip (int8 -> f32, then one multiply per block)."""
@@ -84,6 +90,7 @@ def dequant_flat(pd: PackedDelta) -> jax.Array:
     return (deq * pd.scale[..., None]).reshape(pd.q.shape)
 
 
+@jax.named_scope("fl.pack")
 def unpack_tree(flat, template, qblock: int = QBLOCK):
     """Invert pack_tree: slice (N,) back into ``template``-shaped f32 leaves
     (padding lanes dropped). Caller casts to the target dtype."""

@@ -83,6 +83,7 @@ def freeze_unless(alive, new_state, old_state):
 # Per-client local training (pure; no cross-client communication)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("fl.local_train")
 def local_train(model, model_ctx: AxisCtx, strategy: Strategy, fl: FLConfig,
                 global_params, server_state, client_state, batches, rng,
                 gather_fn=lambda b: b, grad_sync=lambda g: g,
@@ -92,8 +93,10 @@ def local_train(model, model_ctx: AxisCtx, strategy: Strategy, fl: FLConfig,
     Returns (delta, new_client_state, mean_loss). With ``pack_deltas`` the
     delta leaves the client as a ``packing.PackedDelta`` (int8 + block
     scales, via ``Strategy.postprocess_packed``) — what actually crosses the
-    simulated network on the compressed path."""
-    post = strategy.postprocess_packed if pack_deltas else strategy.postprocess
+    simulated network on the compressed path, under the ``fl.pack``
+    scope."""
+    post = (jax.named_scope("fl.pack")(strategy.postprocess_packed)
+            if pack_deltas else strategy.postprocess)
     n_steps = jax.tree.leaves(batches)[0].shape[0]
     use_mom = fl.client_optimizer == "sgdm" and fl.client_momentum > 0
     mom0 = tree_zeros_like(global_params) if use_mom else None
@@ -153,6 +156,7 @@ def local_train(model, model_ctx: AxisCtx, strategy: Strategy, fl: FLConfig,
 # Packed (int8) server-side aggregation
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("fl.aggregate")
 def packed_aggregate(topo, ctx: AxisCtx, pd, weights):
     """Weighted mean of stacked ``PackedDelta``s ((C, N) int8 + (C, N/b)
     scales) through the fused dequant+weighted-sum kernel, following the

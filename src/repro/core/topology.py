@@ -34,6 +34,7 @@ class ClientServer:
     """Star topology: weighted mean of client deltas at the server."""
     name: str = "client_server"
 
+    @jax.named_scope("fl.aggregate")
     def aggregate(self, ctx: AxisCtx, deltas, weights):
         """deltas: (C_loc, ...) per-chip clients; weighted psum over the grid."""
         num = jax.tree.map(
@@ -54,6 +55,7 @@ class Hierarchical:
     can aggregate heterogeneous cohort sizes without bias."""
     name: str = "hierarchical"
 
+    @jax.named_scope("fl.aggregate")
     def aggregate(self, ctx: AxisCtx, deltas, weights):
         """Two-tier aggregation: pod-local means, then the cross-pod mean."""
         num = jax.tree.map(
@@ -103,6 +105,7 @@ class Decentralized:
             state = jax.tree.map(step, state)
         return state
 
+    @jax.named_scope("fl.aggregate")
     def aggregate(self, ctx: AxisCtx, deltas, weights):
         """Gossip-average deltas over the ring for ``gossip_steps``."""
         return self.mix(ctx, deltas)

@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.core import determinism
 from repro.data import partition as part_mod
+from repro.telemetry.recorder import FlightRecorder
 
 
 # ---------------------------------------------------------------------------
@@ -161,6 +162,7 @@ def stage_partitions_dedup(trajectories, keys=None, mesh=None):
     return staged, lane_ds
 
 
+@jax.named_scope("fl.gather")
 def gather_one_client_batch(staged, round_key, client, batch_size: int,
                             n_steps: int):
     """Jittable batch gather for a single (possibly traced) client id.
@@ -221,6 +223,7 @@ def slab_nbytes(slab) -> int:
     return int(sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(slab)))
 
 
+@jax.named_scope("fl.gather")
 def gather_slab_batches(slab_row, round_key, batch_size: int, n_steps: int):
     """Jittable per-round batch gather from one cohort slab row.
 
@@ -239,6 +242,7 @@ def gather_slab_batches(slab_row, round_key, batch_size: int, n_steps: int):
     return jax.vmap(one)(jnp.arange(slab_row["len"].shape[0]))
 
 
+@jax.named_scope("fl.gather")
 def gather_event_batch(row, round_key, client, batch_size: int, n_steps: int):
     """Jittable batch gather from one async event's slab row.
 
@@ -258,10 +262,19 @@ class _Prefetcher:
     not match the pending prefetch (resume, end-of-run remainder) just
     assembles synchronously."""
 
+    # the executor's flight recorder and track (``set_recorder``); a
+    # disabled recorder until then, so the spans cost nothing
+    recorder = FlightRecorder(enabled=False)
+    track = "run"
+
     def __init__(self):
         self.peak_slab_bytes = 0
         self._pool = None
         self._pending = None
+
+    def set_recorder(self, recorder, track: str = "run") -> None:
+        """Record this stager's spans (``cohort_plan``) on ``recorder``."""
+        self.recorder, self.track = recorder, track
 
     def _submit(self, key, fn):
         if self._pool is None:
@@ -310,7 +323,8 @@ class SlabStager(_Prefetcher):
 
         Returns (slots (n, K) int32, real (n, K) float32) — exactly the
         clients ``faults.cohort_mask`` keeps inside the compiled program,
-        because ``select_cohort`` is the same function.
+        because ``select_cohort`` is the same function. Runs under a
+        ``cohort_plan`` span.
         """
         from repro.runtime import faults as faults_mod
         fl = self.fl
@@ -318,16 +332,18 @@ class SlabStager(_Prefetcher):
         ids = np.arange(fl.n_clients)
         slots = np.zeros((n, self.k_slots), np.int32)
         real = np.zeros((n, self.k_slots), np.float32)
-        for i in range(n):
-            kept = faults_mod.select_cohort(self.fault, start + i, ids,
-                                            target, fl.straggler_overprovision)
-            if len(kept) > self.k_slots:
-                raise ValueError(
-                    f"round {start + i} kept {len(kept)} clients but "
-                    f"max_cohort={self.k_slots} slots are staged")
-            slots[i] = kept[0] if len(kept) else 0
-            slots[i, :len(kept)] = kept
-            real[i, :len(kept)] = 1.0
+        with self.recorder.span("cohort_plan", track=self.track):
+            for i in range(n):
+                kept = faults_mod.select_cohort(
+                    self.fault, start + i, ids, target,
+                    fl.straggler_overprovision)
+                if len(kept) > self.k_slots:
+                    raise ValueError(
+                        f"round {start + i} kept {len(kept)} clients but "
+                        f"max_cohort={self.k_slots} slots are staged")
+                slots[i] = kept[0] if len(kept) else 0
+                slots[i, :len(kept)] = kept
+                real[i, :len(kept)] = 1.0
         return slots, real
 
     def widen(self, lmax: int) -> None:
@@ -515,6 +531,12 @@ class StackedSlabStager(_Prefetcher):
                              for l in self.lanes)
         self.resident_bytes = sum(l.resident_bytes for l in self.lanes)
         self.device_bytes = sum(l.device_bytes for l in self.lanes)
+
+    def set_recorder(self, recorder, track: str = "run") -> None:
+        """Record the stack's and every lane's spans on ``recorder``."""
+        super().set_recorder(recorder, track)
+        for lane in self.lanes:
+            lane.set_recorder(recorder, track)
 
     def slab(self, start: int, n: int):
         """The chunk's stacked (S, n, K, ...) slab on device."""

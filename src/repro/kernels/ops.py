@@ -419,6 +419,7 @@ def _pallas_entry(interpret: bool):
     return entry
 
 
+@jax.named_scope("fl.aggregate")
 def quant_aggregate(qdeltas, scales, weights):
     """-> (N,) f32: ``sum_c weights[c] * dequant(qdeltas[c])``.
 

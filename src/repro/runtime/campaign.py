@@ -332,6 +332,7 @@ class CampaignExecutor(Executor):
                                             make_fault(self.job.raw, fl_s))
             lanes.append(cache[k])
         self.stager = StackedSlabStager(lanes)
+        self.stager.set_recorder(self.recorder, self.telemetry_track)
         self.trajectories = [getattr(ln, "data", None) for ln in lanes]
         self.data = self.trajectories
         self.staged = None
@@ -536,63 +537,57 @@ class CampaignExecutor(Executor):
     def _launch_sync(self, start: int, n: int):
         if not self.alive_lanes():
             return self._skip_dead_bucket(n)
-        t0 = time.time()
+        t0 = time.perf_counter()
         prog = self._round_program(n)
-        if self.ragged:
-            staged = self.stager.slab(start, n)
-            self._record_slab_bytes(staged)
-            self._prefetch_next(start, n)
-        else:
-            staged = self.staged
-        args = (self.state, staged, self.roots, self._launch_hyper(),
-                start)
-        if self.recorder.enabled and self._cost_enabled:
-            self._last_program = (n, prog, args)
-        state, metrics = prog(*args)
-        self.state = jax.block_until_ready(state)
-        dt = time.time() - t0
-        self._capture_probes(start, n, metrics.pop("probes", None))
-        cols = self._account_comms(start, n)
-        stacked = {k: np.asarray(v) for k, v in metrics.items()}  # (S, n)
-        self._merge_comms_stacked(stacked, cols)
-        return self._table_rows(stacked, start, n, dt)
+        staged = self._stage_slab(start, n) if self.ragged else self.staged
+        metrics = self._execute(
+            n, prog, (self.state, staged, self.roots, self._launch_hyper(),
+                      start))
+        dt = time.perf_counter() - t0
+        with self.recorder.span("metrics_pull", track=self.telemetry_track):
+            self._capture_probes(start, n, metrics.pop("probes", None))
+            cols = self._account_comms(start, n)
+            stacked = {k: np.asarray(v)
+                       for k, v in metrics.items()}               # (S, n)
+            self._merge_comms_stacked(stacked, cols)
+            return self._table_rows(stacked, start, n, dt)
 
     def _launch_async(self, start: int, n: int):
         if not self.alive_lanes():
             return self._skip_dead_bucket(n)
         epr = self.events_per_round
         n_ev = n * epr
-        t0 = time.time()
+        t0 = time.perf_counter()
         prog = self._event_program(n_ev)
-        args = (self.state, self.staged, self.sched_dev,
-                self._lane_sched_dev, self.roots, self._launch_hyper(),
-                start * epr)
-        if self.recorder.enabled and self._cost_enabled:
-            self._last_program = (("async", n_ev), prog, args)
-        state, metrics = prog(*args)
-        self.state = jax.block_until_ready(state)
-        dt = time.time() - t0
-        probes = self._reduce_async_probes(metrics.pop("probes", None), n)
-        ev = {k: np.asarray(v).reshape(self.S_pad, n, epr)
-              for k, v in metrics.items()}
-        if probes is not None:
-            from repro.core.probes import staleness_hist
-            self._capture_probes(
-                start, n, probes,
-                extra=self._async_probe_extras(start, n),
-                hists={f"probe:staleness_hist:lane{s}": staleness_hist(
-                    ev["staleness"][s], self.job.fl.max_staleness)
-                    for s in self.alive_lanes()})
-        cols = self._account_comms(start, n)
-        stacked = {"loss": ev["loss"].mean(-1),
-                   "staleness": ev["staleness"].mean(-1),
-                   "applied": ev["applied"].sum(-1),
-                   # per-lane virtual arrival time at each round window's
-                   # last event (each lane reads its own schedule): async
-                   # curves plot against virtual time even with comms off
-                   "vtime": self._lane_vtime(start, n)}
-        self._merge_comms_stacked(stacked, cols)
-        return self._table_rows(stacked, start, n, dt)
+        metrics = self._execute(
+            ("async", n_ev), prog,
+            (self.state, self.staged, self.sched_dev, self._lane_sched_dev,
+             self.roots, self._launch_hyper(), start * epr))
+        dt = time.perf_counter() - t0
+        with self.recorder.span("metrics_pull", track=self.telemetry_track):
+            probes = self._reduce_async_probes(metrics.pop("probes", None),
+                                               n)
+            ev = {k: np.asarray(v).reshape(self.S_pad, n, epr)
+                  for k, v in metrics.items()}
+            if probes is not None:
+                from repro.core.probes import staleness_hist
+                self._capture_probes(
+                    start, n, probes,
+                    extra=self._async_probe_extras(start, n),
+                    hists={f"probe:staleness_hist:lane{s}": staleness_hist(
+                        ev["staleness"][s], self.job.fl.max_staleness)
+                        for s in self.alive_lanes()})
+            cols = self._account_comms(start, n)
+            stacked = {"loss": ev["loss"].mean(-1),
+                       "staleness": ev["staleness"].mean(-1),
+                       "applied": ev["applied"].sum(-1),
+                       # per-lane virtual arrival time at each round
+                       # window's last event (each lane reads its own
+                       # schedule): async curves plot against virtual time
+                       # even with comms off
+                       "vtime": self._lane_vtime(start, n)}
+            self._merge_comms_stacked(stacked, cols)
+            return self._table_rows(stacked, start, n, dt)
 
     def _lane_vtime(self, start: int, n: int) -> np.ndarray:
         """(S_pad, n) virtual time at each round window's closing event."""

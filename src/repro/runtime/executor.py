@@ -301,6 +301,7 @@ class Executor:
         if self.ragged:
             self.stager = make_slab_stager(self.job.dataset, fl,
                                            self.job.fault)
+            self.stager.set_recorder(self.recorder, self.telemetry_track)
             self.staged = None
             self.data = getattr(self.stager, "data", None)
             return
@@ -582,27 +583,59 @@ class Executor:
         if nxt > 0:
             self.stager.prefetch(start + n, nxt)
 
-    def _launch_sync(self, start: int, n: int):
-        t0 = time.time()
-        prog = self._round_program(n)
-        if self.ragged:
+    def _stage_slab(self, start: int, n: int):
+        """The ragged chunk's slab, and the kick of the next chunk's
+        prefetch, under a ``stage_slab`` span (the stager's host cohort
+        plan is its ``cohort_plan`` child)."""
+        with self.recorder.span("stage_slab", track=self.telemetry_track):
             staged = self.stager.slab(start, n)
             self._record_slab_bytes(staged)
             self._prefetch_next(start, n)
-        else:
-            staged = self.staged
-        args = (self.state, staged, self.root, self.hyper, start)
-        if self.recorder.enabled and self._cost_enabled:
-            self._last_program = (n, prog, args)
-        state, metrics = prog(*args)
-        self.state = jax.block_until_ready(state)
-        dt = time.time() - t0
-        self._capture_probes(start, n, metrics.pop("probes", None))
-        cols = self._account_comms(start, n)
-        stacked = {k: np.asarray(v) for k, v in metrics.items()}
-        return self._merge_comms(
-            [dict({k: float(v[i]) for k, v in stacked.items()},
-                  round_s=dt / n) for i in range(n)], cols, n)
+        return staged
+
+    def _stage_event_slab(self, e0: int, n_ev: int):
+        """The ragged event window's rows, and the kick of the next
+        window's prefetch, under a ``stage_slab`` span."""
+        epr, clients = self.events_per_round, self.schedule.client
+        with self.recorder.span("stage_slab", track=self.telemetry_track):
+            staged = self.stager.event_slab(clients[e0:e0 + n_ev],
+                                            tag=(e0, n_ev))
+            self._record_slab_bytes(staged)
+            chunk_ev = max(self.job.fl.rounds_per_launch, 1) * epr
+            total_ev = getattr(self, "_run_total", self.job.fl.rounds) * epr
+            nxt = min(chunk_ev, total_ev - (e0 + n_ev))
+            if nxt > 0:
+                self.stager.prefetch_events(
+                    clients[e0 + n_ev:e0 + n_ev + nxt], tag=(e0 + n_ev, nxt))
+        return staged
+
+    def _execute(self, key, prog, args):
+        """One compiled launch: ``dispatch`` (argument transfer, enqueue,
+        and the compile on a cold launch), then ``device_wait`` until the
+        new state is ready. Returns the launch's device metrics."""
+        rec, track = self.recorder, self.telemetry_track
+        if rec.enabled and self._cost_enabled:
+            self._last_program = (key, prog, args)
+        with rec.span("dispatch", track=track):
+            state, metrics = prog(*args)
+        with rec.span("device_wait", track=track):
+            self.state = jax.block_until_ready(state)
+        return metrics
+
+    def _launch_sync(self, start: int, n: int):
+        t0 = time.perf_counter()
+        prog = self._round_program(n)
+        staged = self._stage_slab(start, n) if self.ragged else self.staged
+        metrics = self._execute(
+            n, prog, (self.state, staged, self.root, self.hyper, start))
+        dt = time.perf_counter() - t0
+        with self.recorder.span("metrics_pull", track=self.telemetry_track):
+            self._capture_probes(start, n, metrics.pop("probes", None))
+            cols = self._account_comms(start, n)
+            stacked = {k: np.asarray(v) for k, v in metrics.items()}
+            return self._merge_comms(
+                [dict({k: float(v[i]) for k, v in stacked.items()},
+                      round_s=dt / n) for i in range(n)], cols, n)
 
     def _launch_async(self, start: int, n: int):
         """An async "round" is ``events_per_round`` server events; only the
@@ -610,49 +643,39 @@ class Executor:
         instead of a round scan)."""
         epr = self.events_per_round
         n_ev = n * epr
-        t0 = time.time()
+        t0 = time.perf_counter()
         prog = self._event_program(n_ev)
         e0 = start * epr
-        if self.ragged:
-            staged = self.stager.event_slab(
-                self.schedule.client[e0:e0 + n_ev], tag=(e0, n_ev))
-            self._record_slab_bytes(staged)
-            chunk_ev = max(self.job.fl.rounds_per_launch, 1) * epr
-            total_ev = getattr(self, "_run_total", self.job.fl.rounds) * epr
-            nxt = min(chunk_ev, total_ev - (e0 + n_ev))
-            if nxt > 0:
-                self.stager.prefetch_events(
-                    self.schedule.client[e0 + n_ev:e0 + n_ev + nxt],
-                    tag=(e0 + n_ev, nxt))
-        else:
-            staged = self.staged
-        args = (self.state, staged, self.sched_dev, self.root,
-                self.hyper, e0)
-        if self.recorder.enabled and self._cost_enabled:
-            self._last_program = (("async", n_ev), prog, args)
-        state, metrics = prog(*args)
-        self.state = jax.block_until_ready(state)
-        dt = time.time() - t0
-        probes = self._reduce_async_probes(metrics.pop("probes", None), n)
-        stacked = {k: np.asarray(v).reshape(n, epr)
-                   for k, v in metrics.items()}
-        if probes is not None:
-            self._capture_probes(
-                start, n, probes, extra=self._async_probe_extras(start, n),
-                hists={"probe:staleness_hist": staleness_hist(
-                    stacked["staleness"], self.job.fl.max_staleness)})
-        cols = self._account_comms(start, n)
-        # virtual arrival time at each round window's last event: async
-        # curves plot against virtual time even with comms accounting off
-        vt = self.schedule.vtime
-        return self._merge_comms(
-            [{"loss": float(stacked["loss"][i].mean()),
-              "staleness": float(stacked["staleness"][i].mean()),
-              "applied": float(stacked["applied"][i].sum()),
-              "vtime": float(vt[(start + i + 1) * epr - 1]),
-              "round_s": dt / n,
-              "events_per_s": n_ev / max(dt, 1e-9)}
-             for i in range(n)], cols, n)
+        staged = (self._stage_event_slab(e0, n_ev) if self.ragged
+                  else self.staged)
+        metrics = self._execute(
+            ("async", n_ev), prog,
+            (self.state, staged, self.sched_dev, self.root, self.hyper, e0))
+        dt = time.perf_counter() - t0
+        with self.recorder.span("metrics_pull", track=self.telemetry_track):
+            probes = self._reduce_async_probes(metrics.pop("probes", None),
+                                               n)
+            stacked = {k: np.asarray(v).reshape(n, epr)
+                       for k, v in metrics.items()}
+            if probes is not None:
+                self._capture_probes(
+                    start, n, probes,
+                    extra=self._async_probe_extras(start, n),
+                    hists={"probe:staleness_hist": staleness_hist(
+                        stacked["staleness"], self.job.fl.max_staleness)})
+            cols = self._account_comms(start, n)
+            # virtual arrival time at each round window's last event: async
+            # curves plot against virtual time even with comms accounting
+            # off
+            vt = self.schedule.vtime
+            return self._merge_comms(
+                [{"loss": float(stacked["loss"][i].mean()),
+                  "staleness": float(stacked["staleness"][i].mean()),
+                  "applied": float(stacked["applied"][i].sum()),
+                  "vtime": float(vt[(start + i + 1) * epr - 1]),
+                  "round_s": dt / n,
+                  "events_per_s": n_ev / max(dt, 1e-9)}
+                 for i in range(n)], cols, n)
 
     def _check_async_horizon(self, rounds: int):
         """Horizon grew past the scaffolded schedule? Regenerating is only

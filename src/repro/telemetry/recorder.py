@@ -23,12 +23,25 @@ and ``counter()`` returns immediately — the instrumented drivers pay a
 dict-lookup per chunk boundary, nothing per round. Timing uses
 ``time.perf_counter_ns`` (monotonic); nothing here touches device code, so
 telemetry cannot perturb compiled-program numerics.
+
+An enabled recorder also puts every span on the profiler's clock: the span
+opens a ``jax.profiler.TraceAnnotation`` named ``fl.<span name>``, so under
+any profiler capture (``profile_chunks``, or a capture around the run) the
+program's spans sit in the ``.xplane.pb`` on the same host thread as JAX's
+own events and the device ops.
+
+Each thread keeps its own span stack. A span opened off the thread that
+built the recorder (a stager's prefetch thread) is a root of that thread's
+stack and lands on a track of its own, ``<track>/<thread name>``, so it never
+pops the main thread's spans nor overlaps them on one Perfetto track.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import pathlib
+import threading
 import time
 
 
@@ -37,29 +50,35 @@ class Span:
 
     Its own context manager (not a ``contextlib`` generator): the chunk
     loop opens several spans per chunk boundary, and the hand-rolled
-    ``__enter__``/``__exit__`` pair keeps that on the right side of the
-    recorder's <=5% overhead budget."""
+    ``__enter__``/``__exit__`` pair keeps each span's cost to a few
+    microseconds. ``__enter__`` opens the span's profiler annotation and
+    ``__exit__`` closes it."""
     __slots__ = ("name", "track", "attrs", "id", "parent", "depth", "_t0",
-                 "_rec")
+                 "_rec", "_stack", "_note")
 
-    def __init__(self, rec, name, track, attrs, sid, parent, depth, t0):
+    def __init__(self, rec, name, track, attrs, sid, parent, depth, t0,
+                 stack):
         self.name, self.track, self.attrs = name, track, attrs
         self.id, self.parent, self.depth = sid, parent, depth
         self._t0 = t0
         self._rec = rec
+        self._stack = stack
 
     def __enter__(self):
+        self._note = self._rec._annotation("fl." + self.name)
+        self._note.__enter__()
         return self
 
     def __exit__(self, *exc):
         rec = self._rec
-        rec._stack.pop()
+        dur = rec._now_us() - self._t0
+        self._note.__exit__(None, None, None)
+        self._stack.pop()
         rec._emit({"kind": "span", "id": self.id, "parent": self.parent,
                    "depth": self.depth, "name": self.name,
                    "track": self.track, "t0_us": self._t0,
-                   "dur_us": rec._now_us() - self._t0,
-                   "attrs": dict(self.attrs)})
-        if not rec._stack:
+                   "dur_us": dur, "attrs": dict(self.attrs)})
+        if not self._stack:
             rec.flush()
         return False
 
@@ -93,9 +112,11 @@ class FlightRecorder:
 
     ``out_dir=None`` keeps events in memory only (``self.events``); with an
     out_dir the file is truncated on the recorder's first write (one file
-    per recorder lifetime) and appended per event, flushed whenever the
-    span stack empties. ``profile_chunks`` lists launch ordinals to wrap in
-    a ``jax.profiler.trace`` capture (written under ``out_dir/jax_profile``).
+    per recorder lifetime) and appended per event, flushed whenever a
+    thread's span stack empties. ``profile_chunks`` lists launch ordinals
+    to wrap in a ``jax.profiler.trace`` capture (written under
+    ``out_dir/jax_profile``): one trace holding the launch's ``fl.*`` host
+    spans and its device ops on one clock.
     """
 
     def __init__(self, out_dir=None, run_name: str = "run",
@@ -105,12 +126,17 @@ class FlightRecorder:
         self.out_dir = pathlib.Path(out_dir) if out_dir else None
         self.profile_chunks = frozenset(int(c) for c in profile_chunks)
         self.events: list = []
-        self._stack: list = []
+        self._local = threading.local()   # .stack, .suffix per thread
+        self._home = threading.get_ident()
+        self._lock = threading.Lock()     # events, pending and the file
         self._pending: list = []       # emitted, not yet serialized
-        self._next_id = 0
+        self._ids = itertools.count()
         self._t0_ns = time.perf_counter_ns()
         self._fh = None
         self._profile_warned = False
+        if enabled:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
 
     @classmethod
     def from_job(cls, job, fallback_dir=None) -> "FlightRecorder":
@@ -130,14 +156,24 @@ class FlightRecorder:
         return (time.perf_counter_ns() - self._t0_ns) // 1000
 
     # -- spans ------------------------------------------------------------
+    def _thread_stack(self) -> list:
+        """This thread's open spans; a thread other than the recorder's
+        own also gets its track suffix here."""
+        loc = self._local
+        stack = getattr(loc, "stack", None)
+        if stack is None:
+            stack = loc.stack = []
+            loc.suffix = ("" if threading.get_ident() == self._home
+                          else "/" + threading.current_thread().name)
+        return stack
+
     def span(self, name: str, track: str = "run", **attrs):
         if not self.enabled:
             return _NULL_CTX
-        stack = self._stack
-        sp = Span(self, name, track, attrs, self._next_id,
-                  stack[-1].id if stack else None, len(stack),
-                  self._now_us())
-        self._next_id += 1
+        stack = self._thread_stack()
+        sp = Span(self, name, track + self._local.suffix, attrs,
+                  next(self._ids), stack[-1].id if stack else None,
+                  len(stack), self._now_us(), stack)
         stack.append(sp)
         return sp
 
@@ -176,35 +212,38 @@ class FlightRecorder:
     def _emit(self, event: dict):
         """Record an event; serialization is deferred to ``flush()`` (the
         steady-state cost of an event is two list appends)."""
-        self.events.append(event)
-        if self.out_dir is not None:
-            self._pending.append(event)
+        with self._lock:
+            self.events.append(event)
+            if self.out_dir is not None:
+                self._pending.append(event)
 
     def flush(self):
         """Serialize + write everything emitted since the last flush (one
-        write call), and push it to the OS. Fired whenever the span stack
-        empties — i.e. per chunk boundary — so a crash loses at most the
-        open chunk's events."""
-        if not self._pending:
-            return
-        if self._fh is None:
-            self.out_dir.mkdir(parents=True, exist_ok=True)
-            self._fh = open(self.out_dir / "telemetry.jsonl", "w")
-            self._fh.write(json.dumps(
-                {"kind": "meta", "schema": 1, "run": self.run_name,
-                 "pid": os.getpid(), "unit": "us",
-                 "clock": "perf_counter_ns"}) + "\n")
-        self._fh.write("".join(
-            json.dumps(e, separators=(",", ":")) + "\n"
-            for e in self._pending))
-        self._pending.clear()
-        self._fh.flush()
+        write call), and push it to the OS. Fired whenever a thread's span
+        stack empties — i.e. per chunk boundary — so a crash loses at most
+        the open chunk's events."""
+        with self._lock:
+            if not self._pending:
+                return
+            if self._fh is None:
+                self.out_dir.mkdir(parents=True, exist_ok=True)
+                self._fh = open(self.out_dir / "telemetry.jsonl", "w")
+                self._fh.write(json.dumps(
+                    {"kind": "meta", "schema": 1, "run": self.run_name,
+                     "pid": os.getpid(), "unit": "us",
+                     "clock": "perf_counter_ns"}) + "\n")
+            self._fh.write("".join(
+                json.dumps(e, separators=(",", ":")) + "\n"
+                for e in self._pending))
+            self._pending.clear()
+            self._fh.flush()
 
     def close(self):
         self.flush()
-        if self._fh is not None:
-            self._fh.close()
-            self._fh = None
+        with self._lock:
+            if self._fh is not None:
+                self._fh.close()
+                self._fh = None
 
     def __del__(self):                                # pragma: no cover
         try:

@@ -17,8 +17,10 @@ series under a lane mesh), host RSS/CPU, and quant-agg routing.
 ``report`` collates span *self time* (duration minus enclosed children, so
 nothing double-counts) into the compile/execute/stage/io breakdown the
 paper's dashboard shows, plus a per-track program table. "compile" is the
-launches whose jit-cache count grew during the call (their duration
-includes the first execution — attribution, not a profiler).
+launches whose jit-cache count grew during the call, with every span under
+them (their duration includes the first execution — attribution, not a
+profiler). Under a warm launch, ``stage_slab`` and ``cohort_plan`` count as
+"stage"; ``dispatch``, ``device_wait`` and ``metrics_pull`` as "execute".
 """
 from __future__ import annotations
 
@@ -29,9 +31,13 @@ import sys
 from repro.telemetry.recorder import read_events
 
 # span name -> report category; "launch" splits compile/execute on the
-# per-span compile_delta attr, anything unlisted lands in "other"
+# per-span compile_delta attr (and a cold launch takes its spans along),
+# anything unlisted lands in "other"
 _CATEGORY = {
     "stage_data": "stage", "build_schedule": "stage",
+    "stage_slab": "stage", "cohort_plan": "stage",
+    "dispatch": "execute", "device_wait": "execute",
+    "metrics_pull": "execute",
     "init_state": "init",
     "restore": "io", "checkpoint_save": "io", "ledger": "io", "eval": "io",
     "table_flush": "io", "parquet": "io", "scheduler": "io",
@@ -43,10 +49,25 @@ _CATEGORY_ORDER = ("compile", "execute", "stage", "io", "init", "host",
                    "other")
 
 
-def _span_category(ev: dict) -> str:
+def _cold(ev: dict) -> bool:
+    return ev["name"] == "launch" and ev["attrs"].get("compile_delta", 0) > 0
+
+
+def _compile_spans(spans) -> set:
+    """Ids of the cold launches and every span under them (a parent's id
+    is below its children's: ids are given in open order)."""
+    ids: set = set()
+    for ev in sorted(spans, key=lambda e: e["id"]):
+        if _cold(ev) or ev["parent"] in ids:
+            ids.add(ev["id"])
+    return ids
+
+
+def _span_category(ev: dict, compiling=frozenset()) -> str:
+    if ev["id"] in compiling:
+        return "compile"
     if ev["name"] == "launch":
-        return "compile" if ev["attrs"].get("compile_delta", 0) > 0 \
-            else "execute"
+        return "execute"
     return _CATEGORY.get(ev["name"], "other")
 
 
@@ -111,10 +132,11 @@ def report(run_dir_or_events) -> str:
         return "(no spans recorded)"
     meta = next((e for e in events if e.get("kind") == "meta"), {})
     self_us = _self_times(spans)
+    compiling = _compile_spans(spans)
     cat_us: dict = {}
     cat_n: dict = {}
     for ev in spans:
-        c = _span_category(ev)
+        c = _span_category(ev, compiling)
         cat_us[c] = cat_us.get(c, 0) + max(self_us[ev["id"]], 0)
         cat_n[c] = cat_n.get(c, 0) + 1
     wall_us = max(e["t0_us"] + e["dur_us"] for e in spans) \
