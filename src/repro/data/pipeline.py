@@ -202,7 +202,9 @@ def gather_client_batches(staged, round_key, batch_size: int, n_steps: int):
 # each round consumes one *slab row* — the sampled cohort's data padded to K
 # = max_cohort slots, with the tail zero-weighted. The host replays
 # ``faults.cohort_mask`` (already the bitwise host==program contract) ahead
-# of the launch, so it knows exactly which clients' shards each chunk needs.
+# of the launch, so it knows exactly which clients' shards each chunk needs:
+# one compiled, vmapped call per chunk (``faults.cohort_masks``) on the
+# program's own backend, read back to the host once.
 # Two stagers assemble slabs for the SAME compiled program:
 #
 #   ResidentSlabStager   — root staged on device once, slab gathered on
@@ -323,20 +325,24 @@ class SlabStager(_Prefetcher):
 
         Returns (slots (n, K) int32, real (n, K) float32) — exactly the
         clients ``faults.cohort_mask`` keeps inside the compiled program,
-        because ``select_cohort`` is the same function. Runs under a
-        ``cohort_plan`` span.
+        because ``faults.cohort_masks`` vmaps the same function over the
+        chunk's rounds in one compiled call, read back once. Runs under a
+        ``cohort_plan`` span and records a ``cohort_plan_batch`` counter
+        (``compiled`` is 1 when the call compiled a new plan program).
         """
         from repro.runtime import faults as faults_mod
         fl = self.fl
         target = int(fl.cohort or fl.n_clients)
-        ids = np.arange(fl.n_clients)
         slots = np.zeros((n, self.k_slots), np.int32)
         real = np.zeros((n, self.k_slots), np.float32)
         with self.recorder.span("cohort_plan", track=self.track):
-            for i in range(n):
-                kept = faults_mod.select_cohort(
-                    self.fault, start + i, ids, target,
-                    fl.straggler_overprovision)
+            programs = faults_mod.cohort_mask_programs()
+            masks = np.asarray(faults_mod.cohort_masks(
+                self.fault, np.arange(start, start + n), fl.n_clients,
+                target, fl.straggler_overprovision))
+            compiled = faults_mod.cohort_mask_programs() > programs
+            for i, mask in enumerate(masks):
+                kept = np.flatnonzero(mask > 0)
                 if len(kept) > self.k_slots:
                     raise ValueError(
                         f"round {start + i} kept {len(kept)} clients but "
@@ -344,6 +350,8 @@ class SlabStager(_Prefetcher):
                 slots[i] = kept[0] if len(kept) else 0
                 slots[i, :len(kept)] = kept
                 real[i, :len(kept)] = 1.0
+            self.recorder.counter("cohort_plan_batch", track=self.track,
+                                  rounds=n, compiled=int(compiled))
         return slots, real
 
     def widen(self, lmax: int) -> None:

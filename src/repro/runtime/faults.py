@@ -4,14 +4,16 @@ scaled to 1000+-node thinking).
 The deadline-drop semantics live in ``cohort_mask`` — a *jittable* weight
 mask, so the device-resident multi-round driver (core/rounds.py
 ``build_multi_round``) can select cohorts inside the compiled program with
-no host round-trips. The host-side ``select_cohort`` is a thin wrapper over
-the same function and therefore agrees with the in-program mask bit-for-bit
-(regression-tested in tests/test_driver.py). Deterministic given the seed —
-so fault-tolerance tests can assert bitwise-reproducible recovery.
+no host round-trips. The host-side ``select_cohort`` (one round) and
+``cohort_masks`` (a chunk of rounds, in one compiled call) are thin wrappers
+over the same function and therefore agree with the in-program mask
+bit-for-bit (regression-tested in tests/test_driver.py). Deterministic given
+the seed — so fault-tolerance tests can assert bitwise-reproducible recovery.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -70,6 +72,37 @@ def cohort_mask(fault: FaultModel, round_idx, n_clients: int, target: int,
     rank = jnp.argsort(jnp.argsort(dur))   # rank of each client by duration
     kept = eligible & (rank < target)
     return kept.astype(jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _cohort_masks(draw, rounds, n_clients, target, overprovision):
+    seed, drop_prob, straggler_prob, straggler_slowdown = draw
+    fault = FaultModel(drop_prob=drop_prob, straggler_prob=straggler_prob,
+                       straggler_slowdown=straggler_slowdown, seed=seed)
+    return jax.vmap(lambda r: cohort_mask(fault, r, n_clients, target,
+                                          overprovision))(rounds)
+
+
+def cohort_masks(fault: FaultModel, rounds, n_clients: int, target: int,
+                 overprovision: float = 1.0):
+    """``cohort_mask`` for each absolute round index in ``rounds``, as one
+    compiled call on the default backend: shape (len(rounds), n_clients).
+
+    Only the shapes are static; the fault model's seed and probabilities
+    are traced (as the uint32 and float32 values the in-program mask's
+    constants become), so fault models that differ in them (a seed sweep's
+    lanes) share one executable.
+    """
+    draw = (np.uint32(fault.seed), np.float32(fault.drop_prob),
+            np.float32(fault.straggler_prob),
+            np.float32(fault.straggler_slowdown))
+    return _cohort_masks(draw, np.asarray(rounds, np.int32), int(n_clients),
+                         int(target), float(overprovision))
+
+
+def cohort_mask_programs() -> int:
+    """How many ``cohort_masks`` programs are compiled (the jit cache)."""
+    return _cohort_masks._cache_size()
 
 
 def select_cohort(fault: FaultModel, round_idx: int, client_ids,

@@ -17,7 +17,8 @@ import pytest
 
 from repro.core.jobs import load_job
 from repro.runtime.executor import Executor
-from repro.runtime.faults import FaultModel, cohort_mask, select_cohort
+from repro.runtime.faults import (FaultModel, cohort_mask, cohort_masks,
+                                  select_cohort)
 
 
 def _job(rounds_per_launch: int, placement: str = "spatial",
@@ -83,6 +84,23 @@ def test_cohort_mask_matches_select_cohort():
         kept = select_cohort(fault, r, ids, target=20, overprovision=1.5)
         np.testing.assert_array_equal(np.where(mask > 0)[0], kept)
         assert mask.sum() <= 20
+
+
+@pytest.mark.parametrize("faulty", [True, False], ids=["faults", "clean"])
+@pytest.mark.parametrize("start,n", [(0, 10), (7, 10), (30, 3)],
+                         ids=["first", "later", "remainder"])
+def test_cohort_masks_equal_per_round_mask(faulty, start, n):
+    """The chunk's batched plan is the per-round mask, bitwise, for any
+    chunk start and length (a remainder chunk included)."""
+    fault = (FaultModel(drop_prob=0.1, straggler_prob=0.2, seed=7)
+             if faulty else FaultModel(seed=7))
+    over = 1.5 if faulty else 1.0
+    got = np.asarray(cohort_masks(fault, np.arange(start, start + n), 50,
+                                  20, over))
+    want = np.stack([np.asarray(cohort_mask(fault, r, 50, 20, over))
+                     for r in range(start, start + n)])
+    assert got.shape == (n, 50) and got.dtype == np.float32
+    np.testing.assert_array_equal(got, want)
 
 
 def test_cohort_mask_traced_round_idx():

@@ -26,6 +26,7 @@ import pytest
 from repro.core.jobs import load_job
 from repro.runtime.campaign import CampaignExecutor
 from repro.runtime.executor import Executor
+from repro.runtime.faults import select_cohort
 from repro.telemetry.recorder import read_events
 
 
@@ -82,6 +83,44 @@ def test_ragged_chunked_equals_unchunked():
     p4, l4, _ = _run(_job(streaming=True, rounds_per_launch=4))
     assert l1 == l4
     _assert_bitwise_equal(p1, p4)
+
+
+@pytest.mark.parametrize("start,n", [(0, 2), (3, 5)])
+def test_plan_equals_per_round_select_cohort(start, n):
+    """The stager's one batched plan of a chunk is the slab plan rebuilt
+    round by round from ``select_cohort``: kept ids ascending, pad slots
+    repeating the first kept id, ``real`` on the kept slots only."""
+    stager = Executor(_job()).scaffold().stager
+    fl, k = stager.fl, stager.k_slots
+    slots, real = stager.plan(start, n)
+    want_slots = np.zeros((n, k), np.int32)
+    want_real = np.zeros((n, k), np.float32)
+    for i in range(n):
+        kept = select_cohort(stager.fault, start + i, np.arange(fl.n_clients),
+                             fl.cohort, fl.straggler_overprovision)
+        assert 0 < len(kept) <= k
+        want_slots[i] = kept[0]
+        want_slots[i, :len(kept)] = kept
+        want_real[i, :len(kept)] = 1.0
+    assert slots.dtype == np.int32 and real.dtype == np.float32
+    np.testing.assert_array_equal(slots, want_slots)
+    np.testing.assert_array_equal(real, want_real)
+
+
+def test_seed_lanes_share_one_plan_program():
+    """Campaign lanes that differ only in ``seed`` plan with one compiled
+    program: the first chunk's first lane compiles it, and every later
+    plan call reports ``compiled=0``."""
+    jax.clear_caches()
+    camp = CampaignExecutor(_job(sweep={"seed": [11, 12]},
+                                 telemetry={"cost_analysis": False}))
+    camp.scaffold()
+    camp.run()
+    plans = [e["values"] for e in camp.recorder.events
+             if e["kind"] == "counter" and e["name"] == "cohort_plan_batch"]
+    assert len(plans) >= 2 * 2, plans          # two lanes, two chunks
+    assert [v["compiled"] for v in plans] == [1] + [0] * (len(plans) - 1)
+    assert all(v["rounds"] == 2 for v in plans)
 
 
 def test_async_streaming_equals_resident():

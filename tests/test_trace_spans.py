@@ -118,6 +118,32 @@ def test_profiler_capture_holds_the_spans_nested(tmp_path, mode):
             "cohort_plan": "stage_slab"}.get(s["name"], "launch")
 
 
+def test_cohort_plan_batch_counts_each_chunk_once():
+    """Each chunk's plan is one batched call: one ``cohort_plan_batch``
+    counter per chunk, inside its ``cohort_plan`` span (itself under
+    ``stage_slab``), with ``rounds`` the chunk's round count."""
+    ex = Executor(load_job(_raw(rounds=5, telemetry={
+        "cost_analysis": False}))).scaffold()
+    ex.run()
+    events = ex.recorder.events
+    spans = {e["id"]: e for e in events if e["kind"] == "span"}
+    chunks = [e["attrs"]["n"] for e in sorted(spans.values(),
+                                              key=lambda e: e["id"])
+              if e["name"] == "chunk"]
+    plans = [e for e in events if e["kind"] == "counter"
+             and e["name"] == "cohort_plan_batch"]
+    assert chunks == [2, 2, 1]
+    assert [e["values"]["rounds"] for e in plans] == chunks
+    assert sum(e["values"]["compiled"] for e in plans) <= 2
+    plan_spans = sorted((e for e in spans.values()
+                         if e["name"] == "cohort_plan"),
+                        key=lambda e: e["id"])
+    assert len(plan_spans) == len(plans)
+    for sp, c in zip(plan_spans, plans):
+        assert spans[sp["parent"]]["name"] == "stage_slab"
+        assert sp["t0_us"] <= c["t_us"] <= sp["t0_us"] + sp["dur_us"]
+
+
 def test_recorder_off_puts_no_fl_events(tmp_path):
     ex = Executor(load_job(_raw())).scaffold()
     ex.run(rounds=2)
