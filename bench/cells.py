@@ -1,15 +1,18 @@
 """The benchmark's cells: the entries of ``BENCHMARK.json`` and their files.
 
-A cell names a configuration (``bench/configs/<config>.json``: the model and
-the deployment it runs in) and a traffic mix (``bench/traffic/<traffic>.json``:
-the FL job run over that deployment). ``job_dict`` is the one generator that
-turns the pair and a seed into the job dict ``repro.core.jobs.load_job``
-takes; nothing in it names a particular cell.
+A cell names a configuration (``bench/configs/<config>.json``: the model, its
+family in ``bench/families/`` and the deployment it runs in) and a traffic
+mix (``bench/traffic/<traffic>.json``: the FL job run over that deployment).
+``job_dict`` is the one generator that turns the pair and a seed into the
+job dict ``repro.core.jobs.load_job`` takes; nothing in it names a
+particular cell or model.
 """
 from __future__ import annotations
 
 import json
 import pathlib
+
+from bench import families
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
@@ -36,6 +39,7 @@ def load_cell(name: str, root=ROOT) -> dict:
     entry = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
     conf = read_json(pathlib.Path(root) / configs[entry["config"]]["file"])
+    families.of(conf)
     traffic = read_json(pathlib.Path(root) / "bench" / "traffic"
                         / f"{entry['traffic']}.json")
     limits_path = pathlib.Path(root) / "bench" / "limits" / f"{name}.json"
@@ -69,14 +73,18 @@ def train_params(cell: dict) -> dict:
 
 
 def job_dict(cell: dict, seed: int, telemetry: bool = False) -> dict:
-    """The job dict for ``load_job``: one cell at one seed."""
+    """The job dict for ``load_job``: one cell at one seed. The
+    configuration's optional ``job`` object adds to the ``model`` and
+    ``dataset`` sections (a sequence length, the program's cut)."""
     conf, tr = cell["config"], cell["traffic"]
+    extra = conf.get("job", {})
     tp = dict(train_params(cell), seed=job_seed(seed),
               rounds=int(tr["horizon_rounds"]))
     raw = {"name": cell["name"],
-           "model": {"arch": conf["model"]},
+           "model": {"arch": conf["model"], **extra.get("model", {})},
            "dataset": {"dataset": conf["dataset"],
-                       "n_items": conf["n_items"]},
+                       "n_items": conf["n_items"],
+                       **extra.get("dataset", {})},
            "strategy": {"strategy": tr["strategy"], "train_params": tp},
            "runtime": dict(tr.get("runtime", {}))}
     if tr.get("sweep"):
@@ -108,11 +116,11 @@ def shape(cell: dict) -> dict:
     else:
         per_round = int(tp.get("cohort") or tp["n_clients"])
     n_lanes = len(lanes(cell))
-    images = (int(tp.get("local_epochs", 1)) * int(tp.get("local_steps", 1))
-              * int(tp.get("batch_size", 32)))
+    items = (int(tp.get("local_epochs", 1)) * int(tp.get("local_steps", 1))
+             * int(tp.get("batch_size", 32)))
     return {"rounds_per_call": rpl, "async": asynchronous,
             "updates_per_round": per_round * n_lanes,
             "updates_per_call": rpl * per_round * n_lanes,
-            "images_per_update": images, "lanes": n_lanes,
+            "items_per_update": items, "lanes": n_lanes,
             "int8": tp.get("compression", "none") == "int8",
             "ragged_slots": int(tp.get("max_cohort", 0))}

@@ -70,7 +70,9 @@ def change_gap(prog: list, ref: list, step: int, diff: bool = False) -> float:
 
 def numbers(prog: dict, ref: dict) -> dict:
     """The compared numbers; ``prog`` and ``ref`` as ``reference.run``
-    returns them (losses (lanes, rounds), params per lane)."""
+    returns them (losses (lanes, rounds), params per lane, each a flat
+    dict of leaves by path as ``reference.flat`` makes it, so a nested
+    parameter tree is compared leaf by leaf)."""
     lp = np.asarray(prog["losses"], np.float64)
     lr = np.asarray(ref["losses"], np.float64)
     if lp.shape != lr.shape:

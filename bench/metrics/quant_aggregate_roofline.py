@@ -3,8 +3,9 @@ time its calls could take at the chip's HBM bandwidth (or peak FLOP rate,
 whichever bounds), over the device time of its calls in the traced window.
 A call's time includes the ops XLA put before it to stage its operands in
 VMEM (bench/trace.py), since those do its reads from HBM. The clients per
-call are the cell's slots (sync) or its buffer (FedBuff)."""
-from bench import costs
+call are the cell's slots (sync) or its buffer (FedBuff); the values per
+client, the family's parameter leaves padded to whole blocks."""
+from bench import costs, families
 
 KERNEL = "quant_aggregate"
 
@@ -19,8 +20,9 @@ def read(ctx):
     tp = ctx["cell"]["traffic"]["train_params"]
     clients = int(tp.get("async_buffer") if tp.get("mode") == "async"
                   else tp.get("max_cohort") or tp.get("cohort"))
+    conf = ctx["cell"]["config"]
     cost = costs.quant_aggregate_cost(
-        clients, costs.packed_size(ctx["cell"]["config"]["published"]))
+        clients, costs.packed_size(families.of(conf).leaf_sizes(conf)))
     peaks = ctx["peaks"]
     least = max(cost["bytes"] / peaks["hbm_bytes_per_s"],
                 cost["flops"] / peaks["bf16_flops_per_s"])

@@ -1,12 +1,15 @@
 """Plain reference of the benchmark's FL algorithms: one client at a time.
 
-It imports nothing of the program. From the seed it derives again what the
-program derives: the synthetic images, the client partition, the initial
-weights, each round's cohort, each client's batches and, for async cells,
-the virtual-clock event schedule. Then it trains each client in turn with
-plain SGD in float32 at the highest matrix-product precision, quantizes its
-delta to int8 blocks where the cell compresses, and applies the weighted
-mean (sync) or the staleness-weighted buffer flush (FedBuff) on the server.
+It imports nothing of the program, and nothing here knows the model: the
+cell's family (``bench/families/<family>.py``, named by its configuration)
+derives again from the seed what the program derives of the data, the
+client partition and the initial weights, and trains one client. From the
+seed this module derives each round's cohort, each client's batches and,
+for async cells, the virtual-clock event schedule. It trains each client in
+turn through the family in float32 at the highest matrix-product
+precision, quantizes its delta to int8 blocks where the cell compresses,
+and applies the weighted mean (sync) or the staleness-weighted buffer flush
+(FedBuff) on the server.
 
 ``run`` returns, per lane, the loss of every round and the parameters
 before step 1, after step 1 and after the last step, where a step is one
@@ -27,34 +30,6 @@ QBLOCK = 256
 _F32 = np.float32
 
 
-# -- data ----------------------------------------------------------------
-
-def synthetic_vision(n_items: int, seed: int, shape=(32, 32, 3),
-                     n_classes: int = 10, noise: float = 0.8):
-    """Class prototypes plus Gaussian noise, drawn in the program's order."""
-    rng = np.random.RandomState(seed)
-    y = rng.randint(0, n_classes, n_items)
-    protos = rng.randn(n_classes, *shape).astype(np.float32)
-    x = protos[y] + noise * rng.randn(n_items, *shape).astype(np.float32)
-    return x, y
-
-
-def partition(kind: str, labels, n_clients: int, seed: int) -> list:
-    """Per-client sorted item indices (iid, or 2 label shards)."""
-    rng = np.random.RandomState(seed)
-    if kind == "iid":
-        perm = rng.permutation(len(labels))
-        return [np.sort(p) for p in np.array_split(perm, n_clients)]
-    if kind == "shards":
-        order = np.argsort(labels, kind="stable")
-        shards = np.array_split(order, 2 * n_clients)
-        assign = rng.permutation(len(shards))
-        return [np.sort(np.concatenate([shards[assign[2 * i]],
-                                        shards[assign[2 * i + 1]]]))
-                for i in range(n_clients)]
-    raise KeyError(kind)
-
-
 # -- keys ------------------------------------------------------------------
 
 def _fold(key, *data):
@@ -71,101 +46,40 @@ def cohort(seed: int, rnd: int, n_clients: int, target: int) -> np.ndarray:
     return np.sort(perm[:target])
 
 
-# -- model -------------------------------------------------------------------
+# -- parameter trees -----------------------------------------------------------
 
-def init_params(published: dict, seed: int) -> dict:
-    """Seeded weights N(0, 1/fan_in), zero biases, in the program's key order."""
-    ks = jax.random.split(jax.random.PRNGKey(seed), 12)
-
-    def dense(key, shape, fan_in):
-        return jax.random.normal(key, shape, jnp.float32) * np.float32(
-            1.0 / np.sqrt(fan_in))
-
-    h, w, c = published["input_shape"]
-    classes = published["n_classes"]
-    if "conv_channels" in published:
-        k = published["conv_kernel"]
-        p = {}
-        for i, ch in enumerate(published["conv_channels"]):
-            p[f"c{i + 1}"] = dense(ks[i], (k, k, c, ch), k * k * c)
-            p[f"b{i + 1}"] = jnp.zeros((ch,), jnp.float32)
-            h, w, c = h // 2, w // 2, ch
-        fc, d = published["fc_width"], h * w * c
-        n = len(published["conv_channels"])
-        p["fc"] = dense(ks[n], (d, fc), d)
-        p["fb"] = jnp.zeros((fc,), jnp.float32)
-        p["out"] = dense(ks[n + 1], (fc, classes), fc)
-        p["ob"] = jnp.zeros((classes,), jnp.float32)
-        return p
-    d, width = h * w * c, published["hidden_width"]
-    p = {}
-    for i in range(published["hidden_layers"]):
-        p[f"w{i}"] = dense(ks[i], (d, width), d)
-        p[f"b{i}"] = jnp.zeros((width,), jnp.float32)
-        d = width
-    p["out"] = dense(ks[10], (d, classes), d)
-    p["ob"] = jnp.zeros((classes,), jnp.float32)
-    return p
+def _key(k) -> str:
+    for attr in ("key", "idx", "name"):
+        if hasattr(k, attr):
+            return str(getattr(k, attr))
+    return str(k)
 
 
-def logits(published: dict, p: dict, x):
-    if "conv_channels" in published:
-        h = x
-        for i in range(len(published["conv_channels"])):
-            h = jax.lax.conv_general_dilated(
-                h, p[f"c{i + 1}"], (1, 1), "SAME",
-                dimension_numbers=("NHWC", "HWIO", "NHWC")) + p[f"b{i + 1}"]
-            h = jax.nn.relu(h)
-            h = jax.lax.reduce_window(h, -jnp.inf, jax.lax.max,
-                                      (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
-        h = jax.nn.relu(h.reshape(h.shape[0], -1) @ p["fc"] + p["fb"])
-        return h @ p["out"] + p["ob"]
-    h = x.reshape(x.shape[0], -1)
-    for i in range(published["hidden_layers"]):
-        h = jax.nn.relu(h @ p[f"w{i}"] + p[f"b{i}"])
-    return h @ p["out"] + p["ob"]
+def flat(tree, dtype=None) -> dict:
+    """The leaves of a parameter tree on the host, by path (``layers/0/w``);
+    a flat dict keeps its keys."""
+    leaves, _ = jax.tree_util.tree_flatten_with_path(tree)
+    return {"/".join(_key(k) for k in path): np.asarray(leaf, dtype)
+            for path, leaf in leaves}
 
 
-def make_local_train(published: dict, total_steps: int, n_steps: int,
-                     half_batch: bool):
-    """Jitted: ``total_steps`` SGD steps cycling over ``n_steps`` batches;
-    returns (new params, mean step loss)."""
-    def loss_fn(p, x, y):
-        lp = jax.nn.log_softmax(logits(published, p, x).astype(jnp.float32))
-        return -jnp.take_along_axis(lp, y[:, None], 1).mean()
-
-    def train(p, xs, ys, lr):
-        if half_batch:
-            half = xs.shape[1] // 2
-            xs, ys = xs[:, :half], ys[:, :half]
-
-        def step(p, i):
-            loss, g = jax.value_and_grad(loss_fn)(p, xs[i % n_steps],
-                                                  ys[i % n_steps])
-            return jax.tree.map(lambda a, b: a - lr * b, p, g), loss
-
-        p, losses = jax.lax.scan(step, p, jnp.arange(total_steps))
-        return p, losses.mean()
-
-    return jax.jit(train)
+def _roundtrip_leaf(d):
+    values = d.reshape(-1).astype(jnp.float32)
+    pad = (-values.shape[0]) % QBLOCK
+    blocks = jnp.pad(values, (0, pad)).reshape(-1, QBLOCK)
+    amax = jnp.max(jnp.abs(blocks), axis=1, keepdims=True)
+    scale = jnp.where(amax > 0, amax / 127.0, 1.0)
+    q = jnp.clip(jnp.round(blocks / scale), -127, 127)
+    deq = (q * scale).reshape(-1)[:values.shape[0]]
+    return deq.reshape(d.shape).astype(d.dtype)
 
 
 @jax.jit
-def roundtrip_int8(delta: dict) -> dict:
+def roundtrip_int8(delta):
     """Each leaf in blocks of 256 (zero-padded): q = round(x / (max|x|/127))
     clipped to +-127, sent as q and the block's float32 scale, and
     dequantized as q * scale."""
-    out = {}
-    for name, d in delta.items():
-        flat = d.reshape(-1).astype(jnp.float32)
-        pad = (-flat.shape[0]) % QBLOCK
-        blocks = jnp.pad(flat, (0, pad)).reshape(-1, QBLOCK)
-        amax = jnp.max(jnp.abs(blocks), axis=1, keepdims=True)
-        scale = jnp.where(amax > 0, amax / 127.0, 1.0)
-        q = jnp.clip(jnp.round(blocks / scale), -127, 127)
-        deq = (q * scale).reshape(-1)[:flat.shape[0]]
-        out[name] = deq.reshape(d.shape).astype(d.dtype)
-    return out
+    return jax.tree.map(_roundtrip_leaf, delta)
 
 
 # -- async event schedule ----------------------------------------------------
@@ -279,23 +193,21 @@ def schedule(seed: int, n_clients: int, n_events: int, weights, fl: dict,
 # -- the algorithms ------------------------------------------------------------
 
 def _host(p) -> dict:
-    return {k: np.asarray(v, np.float32) for k, v in p.items()}
+    return flat(p, np.float32)
 
 
-def make_client_update(published: dict, total_steps: int, n_steps: int,
-                       batch: int, int8: bool, half_batch: bool):
+def make_client_update(train, n_steps: int, batch: int, int8: bool):
     """Jitted: one client's round, from its seed-drawn batch positions to
     its (quantized) delta added into the server's weighted sum:
-    (acc, start, x, y, parts, sizes, seed, rnd, client, lr, w) ->
-    (acc, loss)."""
-    train = make_local_train(published, total_steps, n_steps, half_batch)
+    (acc, start, items, parts, sizes, seed, rnd, client, lr, w) ->
+    (acc, loss). ``train`` is the family's local training."""
 
-    def client(acc, start, x, y, parts, sizes, seed, rnd, c, lr, w):
+    def client(acc, start, items, parts, sizes, seed, rnd, c, lr, w):
         key = _fold(jax.random.PRNGKey(seed), rnd, 0xBA7C, c)
         pos = jax.random.randint(key, (n_steps, batch), 0,
                                  jnp.maximum(sizes[c], 1))
         idx = parts[c][pos]
-        new, loss = train(start, x[idx], y[idx], lr)
+        new, loss = train(start, jax.tree.map(lambda a: a[idx], items), lr)
         delta = jax.tree.map(lambda a, b: a - b, new, start)
         if int8:
             delta = roundtrip_int8(delta)
@@ -313,43 +225,48 @@ def run(cell: dict, seed: int, steps: int = 3, dtype=jnp.float32,
     configurations state the program computes, a witness in calibration.
 
     Returns {"losses": (lanes, rounds) array, "params": per lane
-    [before step 1, after step 1, after step ``steps``]}."""
+    [before step 1, after step 1, after step ``steps``], each a flat dict
+    of the parameter tree's leaves by path}."""
+    from bench import families
     from bench.cells import job_seed, lanes, train_params
 
     conf = cell["config"]
+    fam = families.of(conf)
     fl = train_params(cell)
     seed = job_seed(seed)
-    published = conf["published"]
-    x, y = synthetic_vision(conf["n_items"], seed,
-                            tuple(published["input_shape"]),
-                            published["n_classes"])
-    parts = partition(conf["partition"], y, conf["n_clients"], seed)
+    items, parts = fam.data(conf, seed)
     sizes = np.asarray([len(p) for p in parts])
     padded = np.zeros((len(parts), max(int(sizes.max()), 1)), np.int32)
     for c, p in enumerate(parts):
         padded[c, :len(p)] = p
-    data = {"x": jnp.asarray(x, dtype), "y": jnp.asarray(y),
-            "parts": jnp.asarray(padded), "sizes": jnp.asarray(sizes)}
-    del x
+    items = {k: jnp.asarray(v, dtype) if np.issubdtype(v.dtype, np.floating)
+             else jnp.asarray(v) for k, v in items.items()}
+    parts, sizes_dev = jnp.asarray(padded), jnp.asarray(sizes)
     n_steps = max(int(fl.get("local_steps", 1)), 1)
     if precision is None:
         precision = "highest" if dtype == jnp.float32 else "default"
     with jax.default_matmul_precision(precision):
-        update = make_client_update(
-            published, int(fl.get("local_epochs", 1)) * n_steps, n_steps,
-            int(fl.get("batch_size", 32)),
-            fl.get("compression", "none") == "int8", half_batch)
+        train = fam.make_local_train(
+            conf, int(fl.get("local_epochs", 1)) * n_steps, n_steps,
+            half_batch)
+        update = make_client_update(train, n_steps,
+                                    int(fl.get("batch_size", 32)),
+                                    fl.get("compression", "none") == "int8")
         p0 = jax.tree.map(lambda a: a.astype(dtype),
-                          init_params(published, seed))
+                          fam.init_params(conf, seed))
 
         def client(acc, start, rnd, c, lr, w):
-            return update(acc, start, data["x"], data["y"], data["parts"],
-                          data["sizes"], seed, rnd, c, np.asarray(lr, dtype),
-                          np.asarray(w, dtype))
+            return update(acc, start, items, parts, sizes_dev, seed, rnd, c,
+                          np.asarray(lr, dtype), np.asarray(w, dtype))
 
         if fl.get("mode", "sync") == "async":
             return _run_async(cell, fl, seed, steps, sizes, p0, client)
         return _run_sync(fl, seed, steps, sizes, p0, lanes(cell), client)
+
+
+def _mean(losses) -> float:
+    """The mean of clients' losses, in float32 whatever the dtype trained."""
+    return float(np.mean(np.asarray(jnp.stack(losses), np.float32)))
 
 
 def _zeros(p):
@@ -377,7 +294,7 @@ def _run_sync(fl, seed, steps, sizes, p0, lrs, client) -> dict:
                 round_loss.append(loss)
             params[s] = jax.tree.map(lambda p, a: p + a / den, params[s],
                                      acc)
-            losses[s].append(float(np.mean(np.asarray(round_loss))))
+            losses[s].append(_mean(round_loss))
             if (rnd + 1) % rpl == 0 and (rnd + 1) // rpl in (1, steps):
                 kept[s].append(_host(params[s]))
     return {"losses": np.asarray(losses), "params": kept}
@@ -410,8 +327,7 @@ def _run_async(cell, fl, seed, steps, sizes, p0, client) -> dict:
             ring[version % ev["ring"]] = params
             acc = _zeros(p0)
         if (e + 1) % per_round == 0:
-            losses.append(float(np.mean(np.asarray(
-                event_loss[-per_round:]))))
+            losses.append(_mean(event_loss[-per_round:]))
         if (e + 1) % (rpl * per_round) == 0 and \
                 (e + 1) // (rpl * per_round) in (1, steps):
             kept.append(_host(params))

@@ -11,11 +11,13 @@ timing each call on the host clock (a call returns after the state is
 ready and the chunk-boundary work is done).
 
 After the window the peak device memory is read, the program is freed and
-the plain reference (bench/reference.py) recomputes the first three calls;
-bench/compare.py decides ``correct``. ``--trace 1`` turns the flight
-recorder on, traces the first seconds of the window with the JAX profiler
-and reports the cell's per-layer metrics (bench/metrics/<name>.py) instead
-of the end-to-end ones.
+the plain reference (bench/reference.py, with the configuration's family in
+bench/families/) recomputes the first three calls; bench/compare.py decides
+``correct``. ``--trace 1`` turns the flight recorder on, traces the first
+seconds of the window with the JAX profiler, reduces the trace by op, by
+``fl.*`` scope and by span (bench/trace.py, bench/scopes.py) and reports
+the cell's per-layer metrics (bench/metrics/<name>.py) instead of the
+end-to-end ones.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1``
@@ -70,12 +72,12 @@ def device_info(chips: int, on_chip: bool) -> dict:
 
 
 def host_params(ex, lanes: int) -> list:
-    import numpy as np
-    params = ex.state["params"]
+    """Per lane, the parameter tree's leaves on the host by path."""
+    from bench.reference import flat
+    params = flat(ex.state["params"])
     if lanes == 1 and not getattr(ex, "S", 0):
-        return [{k: np.asarray(v) for k, v in params.items()}]
-    return [{k: np.asarray(v[s]) for k, v in params.items()}
-            for s in range(lanes)]
+        return [params]
+    return [{k: v[s] for k, v in params.items()} for s in range(lanes)]
 
 
 def round_losses(ex, lanes: int, since: int) -> list:
@@ -157,6 +159,20 @@ def call_stats(times: list) -> dict:
                               for c in np.array_split(t, 3) if len(c)]}
 
 
+def reduce_trace(compact: dict):
+    """The traced window's reductions (``bench/trace.py`` and, by scope and
+    span, ``bench/scopes.py``) as one dict, and the result's breakdown;
+    (None, None) when the trace holds nothing to read."""
+    from bench import scopes
+    from bench import trace as tracelib
+    reduced = tracelib.reduce(compact)
+    if reduced is None:
+        return None, None
+    reduced.update(scopes.reduce(compact))
+    return reduced, {k: reduced[k] for k in ("device_ops", "idle_gaps",
+                                             "scopes", "idle_spans")}
+
+
 def per_layer(cell, ctx) -> dict:
     out = {}
     for m in cell["per_layer"]:
@@ -179,7 +195,11 @@ def first_steps(cell: dict, seed: int, trace: bool = False):
     shape = cells.shape(cell)
     rpl, lanes = shape["rounds_per_call"], shape["lanes"]
     job = load_job(cells.job_dict(cell, seed, telemetry=trace))
-    ex = (CampaignExecutor if job.sweep is not None else Executor)(job)
+    if job.sweep is not None:
+        ex = CampaignExecutor(job, lane_devices=cell["chips"]
+                              if cell["chips"] > 1 else 0)
+    else:
+        ex = Executor(job)
     ex.scaffold()
     kept = [[p] for p in host_params(ex, lanes)]
     for step in range(1, STEPS + 1):
@@ -200,7 +220,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     import jax
     import numpy as np
 
-    from bench import cells, compare, costs, reference
+    from bench import cells, compare, costs, reference, scopes
     from bench import trace as tracelib
 
     dev = device_info(cell["chips"], on_chip)
@@ -208,6 +228,11 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         from repro.launch.compile_cache import enable_compile_cache
         enable_compile_cache()
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        if trace:
+            # op metadata (the fl.* scopes) is left out of the cache key,
+            # so a cached program would show another build's scopes
+            jax.config.update(
+                "jax_compilation_cache_include_metadata_in_key", True)
     from repro.kernels import ops
 
     shape = cells.shape(cell)
@@ -265,8 +290,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
 
     breakdown = None
     if trace:
-        reduced = tracelib.reduce(tracelib.from_xplane(profile_dir,
-                                                       cell["chips"]))
+        reduced, breakdown = reduce_trace(
+            scopes.from_xplane(profile_dir, cell["chips"]))
         shutil.rmtree(profile_dir, ignore_errors=True)
         ctx = {"trace": reduced, "spans": spans, "window_calls": calls,
                "cell": cell, "shape": shape,
@@ -276,8 +301,6 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         if reduced is not None:
             dev["busy_s"] = reduced["busy_s"]
             dev["window_s"] = reduced["window_s"]
-            breakdown = {"device_ops": reduced["device_ops"],
-                         "idle_gaps": reduced["idle_gaps"]}
     else:
         metrics = {
             "client_updates_per_s": {"value": attempted / sum(times),

@@ -204,3 +204,13 @@ def reduce(compact: dict, top: int = 10) -> dict | None:
 def _close(stack, scopes):
     _, scope, child, dur = stack.pop()
     scopes[scope] = scopes.get(scope, 0.0) + dur - child
+
+
+def per_call_ms(ctx, scope: str):
+    """Device self time of ``scope`` per call of the traced window, in ms;
+    None when the trace holds no op of that scope."""
+    tr = ctx["trace"]
+    if tr is None or not tr.get("chunks"):
+        return None
+    seconds = dict(tr.get("scopes", [])).get(scope)
+    return None if seconds is None else 1e3 * seconds / tr["chunks"]

@@ -23,10 +23,17 @@ def files_cell(config: str, traffic: str) -> dict:
 
 
 def shrink(cell: dict, keep_steps: bool = False) -> dict:
-    """``cell`` with its population, cohort, batch and (unless
-    ``keep_steps``) local steps cut down; model widths and the traffic's
-    algorithm stay."""
-    cell["config"].update(n_items=240, n_clients=12)
+    """``cell`` over its family's ``tiny`` cut of its configuration, with
+    its cohort, batch and (unless ``keep_steps``) local steps cut down; the
+    traffic's algorithm stays."""
+    from bench import families
+    cell["config"] = families.of(cell["config"]).tiny(cell["config"])
+    return cut_traffic(cell, keep_steps)
+
+
+def cut_traffic(cell: dict, keep_steps: bool = False) -> dict:
+    """``cell`` with its cohort, batch and (unless ``keep_steps``) local
+    steps cut down."""
     tr = cell["traffic"]
     tp = tr["train_params"]
     tp["batch_size"] = 4
@@ -44,15 +51,10 @@ def shrink(cell: dict, keep_steps: bool = False) -> dict:
     return cell
 
 
-def tiny_cell(name: str, config: str | None = None,
-              keep_steps: bool = False) -> dict:
-    """Cell ``name`` of BENCHMARK.json, optionally over another
-    configuration, cut down by ``shrink``."""
-    from bench.cells import BENCH, load_cell, read_json
-    cell = load_cell(name)
-    if config is not None:
-        cell["config"] = read_json(BENCH / "configs" / f"{config}.json")
-    return shrink(cell, keep_steps)
+def tiny_cell(name: str, keep_steps: bool = False) -> dict:
+    """Cell ``name`` of BENCHMARK.json cut down by ``shrink``."""
+    from bench.cells import load_cell
+    return shrink(load_cell(name), keep_steps)
 
 
 def names() -> list:
@@ -68,7 +70,7 @@ def run_tiny(name, trace=False):
     """One run of cell ``name`` at a tiny size on the CPU; checks the shape
     of the printed lines and returns the result."""
     from bench import run
-    cell = tiny_cell(name, config="flsim-mlp-cifar10-xdevice")
+    cell = tiny_cell(name)
     out, err = io.StringIO(), io.StringIO()
     result = run.run_cell(cell, SEED, 0.3, trace, on_chip=False, out=out,
                           err=err)

@@ -40,7 +40,7 @@ def test_cell_files_load_and_pass_load_job(name):
     cell = cells.load_cell(name)
     entry = {w["name"]: w for w in BENCH["workloads"]}[name]
     assert cell["config"]["name"] == entry["config"]
-    assert cell["chips"] == 1
+    assert cell["chips"] in (1, 4)
     job = load_job(cells.job_dict(cell, 2**31 + 5))
     assert job.arch == cell["config"]["model"]
     assert job.fl.n_clients == cell["config"]["n_clients"]
@@ -59,9 +59,40 @@ def test_every_per_layer_metric_has_a_reader(metric):
     assert callable(mod.read)
 
 
+def cut_faults(entry: dict, conf: dict) -> list:
+    """What a configuration file and its entry in BENCHMARK.json leave
+    unstated of their cuts: each key cut gives its published value beside
+    the cut (under ``published``), and a cut file states the deployment
+    whose share of a chip it is."""
+    faults = []
+    if conf.get("reduced") != entry["reduced"]:
+        faults.append("reduced differs between the file and its entry")
+    faults += [f"{k}: no published value" for k in entry["reduced"]
+               if k not in conf.get("published", {})]
+    if entry["reduced"] and not conf.get("deployment"):
+        faults.append("no deployment")
+    return faults
+
+
 def test_config_files_state_their_cuts():
     for c in BENCH["configs"]:
         conf = cells.read_json(cells.ROOT / c["file"])
         assert conf["name"] == c["name"]
-        assert conf["reduced"] == c["reduced"] == []
         assert c["file"].startswith("bench/configs/")
+        assert cut_faults(c, conf) == []
+
+
+@pytest.mark.parametrize("conf,faults", [
+    ({"reduced": ["num_hidden_layers"], "num_hidden_layers": 6,
+      "published": {"num_hidden_layers": 27},
+      "deployment": "8-way expert parallel"}, 0),
+    ({"reduced": ["num_hidden_layers"], "num_hidden_layers": 6,
+      "published": {}, "deployment": "8-way expert parallel"}, 1),
+    ({"reduced": ["num_hidden_layers"], "num_hidden_layers": 6,
+      "published": {"num_hidden_layers": 27}}, 1),
+    ({"reduced": [], "published": {"num_hidden_layers": 27},
+      "deployment": "8-way expert parallel"}, 1)],
+    ids=["stated", "no-published-value", "no-deployment", "entry-differs"])
+def test_a_cut_is_accepted_with_its_published_values(conf, faults):
+    entry = {"reduced": ["num_hidden_layers"]}
+    assert len(cut_faults(entry, conf)) == faults

@@ -54,3 +54,19 @@ def test_judge_fails_a_number_without_a_limit_and_skips_an_uncompared():
     assert "first_loss_gap" not in got
     assert got.pop("loss_gap") is False
     assert all(got.values())
+
+
+def test_trees_are_compared_leaf_by_leaf_by_path():
+    from bench.reference import flat
+    assert list(flat({"w0": np.ones(2), "b0": np.zeros(2)})) == ["b0", "w0"]
+    tree = {"layers": [{"w": np.ones(3)}, {"w": np.zeros(3)}],
+            "head": {"b": np.ones(1)}}
+    got = flat(tree)
+    assert list(got) == ["head/b", "layers/0/w", "layers/1/w"]
+    moved = flat({"layers": [{"w": np.ones(3)}, {"w": np.full(3, 2.0)}],
+                  "head": {"b": np.ones(1)}})
+    # the program leaves layers/1/w where the reference moves it
+    nums = compare.numbers({"losses": [[1.0]], "params": [[got, got, got]]},
+                           {"losses": [[1.0]], "params": [[got, moved,
+                                                           moved]]})
+    assert nums["step1_change_gap"] == pytest.approx(1.0)

@@ -8,7 +8,8 @@ import pytest
 
 from bench import scopes, spans
 from bench import trace as tracelib
-from bench.metrics import cohort_plan_ms, metrics_pull_ms, slab_stage_ms
+from bench.metrics import aggregate_ms, cohort_plan_ms, local_train_ms, \
+    metrics_pull_ms, pack_ms, slab_stage_ms
 
 DATA = pathlib.Path(__file__).resolve().parent / "data"
 
@@ -212,3 +213,48 @@ def test_span_readers_find_nothing_without_their_span():
     for ctx in (_ctx(events), _ctx([]), _ctx(handmade_spans(), calls=0)):
         assert all(m.read(ctx) is None for m in
                    (cohort_plan_ms, slab_stage_ms, metrics_pull_ms))
+
+
+def test_run_reduces_a_trace_by_op_scope_and_span():
+    """``run.reduce_trace``: one dict for the readers, with the scopes,
+    and the breakdown the result line carries."""
+    from bench import run
+    reduced, breakdown = run.reduce_trace(handmade())
+    assert reduced["busy_s"] == pytest.approx(90e-9)
+    assert dict(reduced["scopes"]) == dict(scopes.reduce(handmade())
+                                           ["scopes"])
+    assert set(breakdown) == {"device_ops", "idle_gaps", "scopes",
+                              "idle_spans"}
+    assert all(len(v) <= 10 for v in breakdown.values())
+    c = handmade()
+    c["devices"][0]["events"] = []
+    assert run.reduce_trace(c) == (None, None)
+
+
+@pytest.mark.parametrize("reader,ns", [(local_train_ms, 40),
+                                       (pack_ms, 10), (aggregate_ms, 20)])
+def test_scope_readers_per_call(reader, ns):
+    from bench import run
+    reduced, _ = run.reduce_trace(handmade())
+    assert reader.read({"trace": reduced}) == pytest.approx(ns * 1e-6)
+    reduced["chunks"] = 2
+    assert reader.read({"trace": reduced}) == pytest.approx(ns * 1e-6 / 2)
+    reduced["scopes"] = [s for s in reduced["scopes"] if s[0] == "other"]
+    assert reader.read({"trace": reduced}) is None
+    assert reader.read({"trace": None}) is None
+
+
+def test_recorded_chip_trace_feeds_the_scope_readers():
+    """Scope seconds of the recorded traced call add up to its busy time,
+    and each scope reader reads its share of it."""
+    from bench import run
+    compact = json.loads((DATA / "trace_mlp_int8_scopes_v5e.json")
+                         .read_text())
+    reduced, breakdown = run.reduce_trace(compact)
+    got = {m.__name__.rsplit(".", 1)[1]: m.read({"trace": reduced})
+           for m in (local_train_ms, pack_ms, aggregate_ms)}
+    assert all(v > 0 for v in got.values()), got
+    assert sum(t for _, t in breakdown["scopes"]) == pytest.approx(
+        reduced["busy_s"], rel=1e-9)
+    assert got["pack_ms"] == pytest.approx(
+        1e3 * dict(reduced["scopes"])["fl.pack"] / reduced["chunks"])
